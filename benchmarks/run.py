@@ -65,10 +65,11 @@ def bench_kernels():
     us = _time(lambda: ops.rglru_scan(la, b, impl="xla"))
     row("kernel.rglru_scan.xla.1024", us)
 
-    x = jax.random.normal(key, (1_000_000,))
+    x = jax.random.normal(key, (1, 1_000_000))
     nb = jax.random.normal(key, (2, 1_000_000))
-    sig = jnp.array([0.3, 0.3])
-    us = _time(lambda: ops.consensus_update(x, nb, sig, impl="xla"))
+    idx = jnp.array([[0, 1]])
+    sig = jnp.array([[0.3, 0.3]])
+    us = _time(lambda: ops.consensus_update(x, nb, idx, sig, impl="xla"))
     row("kernel.consensus_update.xla.1M", us)
 
 

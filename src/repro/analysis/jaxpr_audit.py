@@ -63,20 +63,18 @@ _PASSTHROUGH = {"reshape", "transpose", "broadcast_in_dim", "squeeze",
 # ---------------------------------------------------------------------------
 
 def _source_of(eqn):
-    """(file, line) of the user frame that emitted ``eqn`` (best effort).
-    Paths are cut down to repo-relative (``src/...``) so findings — and
-    the committed baseline keyed on them — match across checkouts."""
-    try:
-        from jax._src import source_info_util
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is not None:
-            f = fr.file_name.replace("\\", "/")
-            if "/src/repro/" in f:
-                f = "src/repro/" + f.rsplit("/src/repro/", 1)[1]
-            return f, int(fr.start_line)
-    except Exception:
-        pass
-    return "<jaxpr>", 0
+    """(file, line) of the user frame that emitted ``eqn``, or
+    ``("<jaxpr>", 0)`` when the equation carries no traceback. Paths are
+    cut down to repo-relative (``src/...``) so findings — and the
+    committed baseline keyed on them — match across checkouts."""
+    from jax._src import source_info_util
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is None:
+        return "<jaxpr>", 0
+    f = fr.file_name.replace("\\", "/")
+    if "/src/repro/" in f:
+        f = "src/repro/" + f.rsplit("/src/repro/", 1)[1]
+    return f, int(fr.start_line)
 
 
 def _sub_closed_jaxprs(eqn):

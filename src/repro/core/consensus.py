@@ -334,16 +334,12 @@ def consensus_step(stacked_params, mix, *, impl: str = "xla",
     kernel_impl = ("pallas" if jax.default_backend() == "tpu"
                    else "interpret") if use_pallas else "xla"
 
+    kw = {} if block_n is None else {"block_n": block_n}
+
     def mix_leaf(x):
         K = x.shape[0]
         xf = x.astype(jnp.float32).reshape(K, -1)
-        kw = {} if block_n is None else {"block_n": block_n}
-
-        def one(xk, ik, sk):
-            return ops.consensus_update(xk, xf[ik], sk, impl=kernel_impl,
-                                        **kw)
-
-        y = jax.vmap(one)(xf, idx, sig)
+        y = ops.consensus_update(xf, xf, idx, sig, impl=kernel_impl, **kw)
         return y.reshape(x.shape).astype(x.dtype)
 
     return jax.tree.map(mix_leaf, stacked_params)
@@ -437,19 +433,12 @@ def _compressed_consensus_step(stacked_params, mix, codec, codec_state,
             qkw = dict(kw) if base.block is None \
                 else dict(kw, qblock=base.block)
 
-            def one(xk, qk, sk, ik, sgk):
-                return ops.quant_consensus_update(
-                    xk, qk, sk, q[ik], s[ik], sgk,
-                    impl=kernel_impl, **qkw)
-
-            y = jax.vmap(one)(xf, q, s, idx, sig)
+            y = ops.quant_consensus_update(
+                xf, q, s, q, s, idx, sig, impl=kernel_impl, **qkw)
         elif sparse:
-            def one(xk, xhk, ik, sgk):
-                mixed_hat = ops.consensus_update(
-                    xhk, xhat[ik], sgk, impl=kernel_impl, **kw)
-                return xk + (mixed_hat - xhk)
-
-            y = jax.vmap(one)(xf, xhat, idx, sig)
+            mixed_hat = ops.consensus_update(
+                xhat, xhat, idx, sig, impl=kernel_impl, **kw)
+            y = xf + (mixed_hat - xhat)
         else:
             y = xf + off @ xhat - rowsum[:, None] * xhat
 
@@ -479,15 +468,6 @@ def consensus_error(stacked_params) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis. ``jax.lax.axis_size`` only exists on
-    newer jax; ``psum(1, name)`` constant-folds to a Python int under both
-    vmap and shard_map on every version we support."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ring_consensus_step(params, data_size, axis_name: str, hops: int = 1,
                         include_self: bool = True, message_dtype=None):
     """One Eq.-(6) round where each ``axis_name`` position is an agent.
@@ -502,7 +482,7 @@ def ring_consensus_step(params, data_size, axis_name: str, hops: int = 1,
     the ppermute (XLA otherwise commutes converts past permutes and keeps
     the wire at the storage dtype — EXPERIMENTS.md §Perf P3).
     """
-    K = _axis_size(axis_name)
+    K = jax.lax.axis_size(axis_name)
     perms = []
     for d in range(1, hops + 1):
         perms.append([(i, (i + d) % K) for i in range(K)])   # from left
@@ -700,7 +680,6 @@ def distributed_consensus_step(stacked_params, mix, *,
                                    stateful=stateful, pin_wire=use_mesh)
 
     if use_mesh:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         spec = PartitionSpec(axis_name)
@@ -711,9 +690,9 @@ def distributed_consensus_step(stacked_params, mix, *,
             un = lambda t: jax.tree.map(lambda a: a[None], t)
             return un(out), un(res)
 
-        new, res = shard_map(
+        new, res = jax.shard_map(
             block_fn, mesh=mesh, in_specs=(spec,) * 4,
-            out_specs=(spec, spec), check_rep=False)(
+            out_specs=(spec, spec), check_vma=False)(
             stacked_params, codec_state, sig_stack, keys)
     else:
         new, res = jax.vmap(agent_fn, axis_name=axis_name)(
@@ -771,23 +750,15 @@ def _sharded_block_leaf(x_blk, r_blk, idx_blk, sig_blk, keys_blk, *, K: int,
         qblock = getattr(base, "block", None)
         qkw = dict(kw) if qblock is None else dict(kw, qblock=qblock)
 
-        def one(xk, qk, sk, ik, sgk):
-            return ops.quant_consensus_update(
-                xk, qk, sk, gathered["q"][ik], gathered["scale"][ik], sgk,
-                impl=kernel_impl, **qkw)
-
-        y = jax.vmap(one)(x_blk, payload["q"], payload["scale"],
-                          idx_blk, sig_blk)
+        y = ops.quant_consensus_update(
+            x_blk, payload["q"], payload["scale"], gathered["q"],
+            gathered["scale"], idx_blk, sig_blk, impl=kernel_impl, **qkw)
     else:
         xhat_all = (gathered["v"] if codec is None else
                     jax.vmap(lambda p: codec.decode_leaf(p, like))(gathered))
-
-        def one(xk, xhk, ik, sgk):
-            mixed_hat = ops.consensus_update(xhk, xhat_all[ik], sgk,
-                                             impl=kernel_impl, **kw)
-            return xk + (mixed_hat - xhk)
-
-        y = jax.vmap(one)(x_blk, xhat_blk, idx_blk, sig_blk)
+        mixed_hat = ops.consensus_update(xhat_blk, xhat_all, idx_blk,
+                                         sig_blk, impl=kernel_impl, **kw)
+        y = x_blk + (mixed_hat - xhat_blk)
     return y, r_new
 
 
@@ -859,13 +830,13 @@ def sharded_consensus_step(stacked_params, mix, *, num_blocks: int,
         """Map ``fn`` over the block axis: shard_map on a real mesh,
         vmap(axis_name) emulation otherwise. args are (K, ...) or None."""
         if use_mesh:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec
 
             spec = PartitionSpec(axis_name)
-            return shard_map(fn, mesh=mesh, in_specs=(spec,) * len(args),
-                             out_specs=(spec, spec),
-                             check_rep=False)(*args)
+            return jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(spec,) * len(args),
+                                 out_specs=(spec, spec),
+                                 check_vma=False)(*args)
         blk = jax.tree.map(
             lambda a: a.reshape((num_blocks, B) + a.shape[1:]), args)
         out, res = jax.vmap(fn, axis_name=axis_name)(*blk)
@@ -899,7 +870,7 @@ def cluster_ring_consensus_step(params, data_size, axis_name: str,
     """Ring consensus restricted to contiguous clusters of ``cluster_size``
     agents along ``axis_name`` (the paper's per-task clusters C_i: only
     same-cluster agents exchange models)."""
-    K = _axis_size(axis_name)
+    K = jax.lax.axis_size(axis_name)
     assert K % cluster_size == 0
     if cluster_size == 1:
         return params

@@ -22,11 +22,11 @@ are shared:
 * :func:`traceable` — the ``sample_tasks_traced`` contract probe: a
   sampler that traces under abstract (key, round) arguments — AND whose
   output actually depends on them — runs INSIDE the scan; anything
-  else (host RNG, ``int(t)`` round logic, file I/O, stateful iterators
-  whose trace would bake one batch in as a constant) is transparently
-  wrapped in ``jax.pure_callback`` so the scanned drivers accept every
-  sampler the host-loop drivers did, at the cost of one host round-trip
-  per round for that sampler only.
+  else (host RNG, ``int(t)`` round logic, stateful iterators whose
+  trace would bake one batch in as a constant) is wrapped in
+  ``jax.pure_callback`` — with a one-time warning naming it — so the
+  scanned drivers accept every sampler the host-loop drivers did, at
+  the cost of one host round-trip per round for that sampler only.
 * :func:`first_hit` — recover the EXACT first round that hit the target
   from a per-round reached mask (the scanned FL driver freezes state
   with ``lax.cond`` once the target is reached, so t_i is bit-identical
@@ -51,14 +51,18 @@ are shared:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
+import logging
 import weakref
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -106,6 +110,27 @@ def clear_program_registry():
     _PROGRAM_REFS.clear()
 
 
+#: strong references held by an active :func:`retained_programs` block
+_RETAINED: Optional[list] = None
+
+
+@contextlib.contextmanager
+def retained_programs():
+    """Keep every :func:`donating_jit` program built inside the block
+    alive, and yield the list their :class:`ProgramRecord`\\ s land in —
+    for auditing drivers whose programs are local to one call (the
+    weakref'd registry drops those as soon as the call returns)."""
+    global _RETAINED
+    outer, kept = _RETAINED, []
+    _RETAINED = kept
+    records = []
+    try:
+        yield records
+    finally:
+        _RETAINED = outer
+        records.extend(d._program_record for d in kept)
+
+
 def _abstractify(tree):
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
@@ -141,6 +166,8 @@ def donating_jit(fn: Callable, donate_argnums=(), **jit_kwargs):
 
     dispatch._program_record = rec
     _PROGRAM_REFS.append(weakref.ref(dispatch))
+    if _RETAINED is not None:
+        _RETAINED.append(dispatch)
     return dispatch
 
 
@@ -174,6 +201,10 @@ def _outputs_all_constant(closed_jaxpr) -> bool:
                for v in j.outvars)
 
 
+#: names whose host-callback fallback has been logged (once each)
+_FELL_BACK: set = set()
+
+
 def traceable(fn: Callable, *probe_args, name: str = "sampler"):
     """Return a scan-safe version of ``fn`` plus whether it traced.
 
@@ -183,11 +214,14 @@ def traceable(fn: Callable, *probe_args, name: str = "sampler"):
     (pure jax ops, no host concretization of the round index or key)
     and it is returned as-is to run on-device inside the scan.
 
-    Everything else falls back: functions that fail to trace, and
-    traceable-but-impure ones whose outputs are input-independent
-    constants (a stateful ``next(batch_iter)`` sampler would otherwise
-    silently bake ONE batch into the compiled loop). The fallback calls
-    ``fn`` once CONCRETELY to learn the output structure, then wraps it
+    Two cases fall back: functions that fail to trace because they
+    concretize a tracer (host RNG, ``int(t)`` round logic — JAX's
+    tracer type and index errors), and traceable-but-impure ones whose
+    outputs are input-independent constants (a stateful
+    ``next(batch_iter)`` sampler would otherwise silently bake ONE batch
+    into the compiled loop). Any other exception is a bug in ``fn`` and
+    propagates. The fallback is logged once per ``name``, then calls
+    ``fn`` once CONCRETELY to learn the output structure and wraps it
     in ``jax.pure_callback``: the scanned loop stays one compiled
     program, and this one function round-trips to the host each round
     with concrete (numpy) arguments — exactly the values the host-loop
@@ -198,8 +232,13 @@ def traceable(fn: Callable, *probe_args, name: str = "sampler"):
     try:
         if not _outputs_all_constant(jax.make_jaxpr(fn)(*probe_args)):
             return fn, True
-    except Exception:
-        pass
+        why = "its outputs do not depend on its inputs"
+    except (jax.errors.JAXTypeError, jax.errors.JAXIndexError) as e:
+        why = f"it does not trace ({type(e).__name__})"
+    if name not in _FELL_BACK:
+        _FELL_BACK.add(name)
+        log.warning("%s %r runs as a host callback every round: %s",
+                    name, getattr(fn, "__name__", fn), why)
     out = fn(*probe_args)
     sds = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),

@@ -60,52 +60,95 @@ def rglru_scan(log_a, b, h0=None, *, block_w: int = 512, block_t: int = 256,
                           interpret=(impl == "interpret"))
 
 
+def _fold_agents(kernel, n_own: int, n_src: int):
+    """Make a population kernel map over an outer ``vmap`` by folding the
+    mapped axis into its agent axis (agents are independent rows), so a
+    vmapped call still lowers to ONE ``pallas_call`` with the same block
+    shapes — the sharded plan's single-program emulation vmaps over
+    agent blocks. ``kernel(*own, *src, idx, sigmas)`` takes ``n_own``
+    per-agent arrays and ``n_src`` source arrays, all row-major on their
+    leading axis; the folded ``idx`` is offset into the folded source."""
+    f = jax.custom_batching.custom_vmap(kernel)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        own, src = args[:n_own], args[n_own:n_own + n_src]
+        idx, sig = args[n_own + n_src:]
+        b_own = in_batched[:n_own]
+        b_src, b_idx, b_sig = (in_batched[n_own:n_own + n_src],
+                               *in_batched[n_own + n_src:])
+        spread = lambda a, b: (a if b else jnp.broadcast_to(
+            a[None], (axis_size,) + a.shape))
+        merge = lambda a: a.reshape((-1,) + a.shape[2:])
+        own = [merge(spread(a, b)) for a, b in zip(own, b_own)]
+        idx = spread(idx, b_idx)
+        if any(b_src):
+            src = [spread(a, b) for a, b in zip(src, b_src)]
+            rows = src[0].shape[1]
+            idx = idx + rows * jnp.arange(axis_size,
+                                          dtype=idx.dtype)[:, None, None]
+            src = [merge(a) for a in src]
+        out = f(*own, *src, merge(idx), merge(spread(sig, b_sig)))
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return f
+
+
 @functools.partial(jax.jit, static_argnames=("block_n", "impl"))
-def consensus_update(x, neighbors, sigmas, *, block_n: int = 64 * 1024,
+def consensus_update(x, src, idx, sigmas, *, block_n: int = 64 * 1024,
                      impl: str = "xla"):
-    """Fused Eq.-(6) update: x + Σ_h σ_h (neighbors_h − x), flat params."""
-    _check_dtype(x, neighbors)
-    if neighbors.ndim != 2 or neighbors.shape[1] != x.shape[0] \
-            or sigmas.shape[0] != neighbors.shape[0]:
-        raise ValueError(
-            f"bad shapes {x.shape} {neighbors.shape} {sigmas.shape}")
+    """Fused Eq.-(6) update of K agents over flat params:
+    x_k + Σ_h σ_kh (src[idx_kh] − x_k). x (K, N); src (M, N) the models
+    neighbours are read from; idx (K, H) int rows of src; sigmas
+    (K, H)."""
+    _check_dtype(x, src)
+    K = x.shape[0]
+    if (x.ndim != 2 or src.ndim != 2 or src.shape[1] != x.shape[1]
+            or idx.ndim != 2 or idx.shape[0] != K
+            or sigmas.shape != idx.shape):
+        raise ValueError(f"bad shapes {x.shape} {src.shape} {idx.shape} "
+                         f"{sigmas.shape}")
     if impl == "xla":
-        return _ref.consensus_update_reference(x, neighbors, sigmas)
-    return _cu.consensus_update(x, neighbors, sigmas, block_n=block_n,
-                                interpret=(impl == "interpret"))
+        return jax.vmap(_ref.consensus_update_reference)(x, src[idx],
+                                                         sigmas)
+    kernel = functools.partial(_cu.consensus_update, block_n=block_n,
+                               interpret=(impl == "interpret"))
+    return _fold_agents(kernel, 1, 1)(x, src, idx, sigmas)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "impl", "qblock"))
-def quant_consensus_update(x, q_self, s_self, q_neighbors, s_neighbors,
-                           sigmas, *, block_n: int = 64 * 1024,
-                           impl: str = "xla", qblock=None):
-    """Fused int-dequant + Eq.-(6) update around the agent's own decoded
-    model: x + Σ_h σ_h (s_h·q_h − s_self·q_self). Wire models ride int8
-    lanes. ``qblock=None``: one scale per model (s_self scalar,
-    s_neighbors (H,)); ``qblock=B``: per-channel block-wise scales
-    (``"int8:b64"`` wires) — s_self (⌈N/B⌉,), s_neighbors (H, ⌈N/B⌉)."""
+def quant_consensus_update(x, q_self, s_self, q_src, s_src, idx, sigmas, *,
+                           block_n: int = 64 * 1024, impl: str = "xla",
+                           qblock=None):
+    """Fused int-dequant + Eq.-(6) update of K agents around each agent's
+    own decoded model: x_k + Σ_h σ_kh (s_j·q_j − s_k·q_k), j = idx_kh.
+    Wire models ride int8 lanes: q_self (K, N), q_src (M, N) the wires
+    neighbours are read from, idx (K, H) int rows of q_src, sigmas
+    (K, H). ``qblock=None``: one scale per model (s_self (K,), s_src
+    (M,)); ``qblock=B``: per-channel block-wise scales (``"int8:b64"``
+    wires) — s_self (K, ⌈N/B⌉), s_src (M, ⌈N/B⌉)."""
     _check_dtype(x)
-    if q_self.dtype != jnp.int8 or q_neighbors.dtype != jnp.int8:
+    if q_self.dtype != jnp.int8 or q_src.dtype != jnp.int8:
         raise TypeError(
-            f"wire models must be int8, got {q_self.dtype} "
-            f"{q_neighbors.dtype}")
-    if (q_neighbors.ndim != 2 or q_neighbors.shape[1] != x.shape[0]
-            or q_self.shape != x.shape
-            or s_neighbors.shape[0] != q_neighbors.shape[0]
-            or sigmas.shape[0] != q_neighbors.shape[0]):
+            f"wire models must be int8, got {q_self.dtype} {q_src.dtype}")
+    K, M = x.shape[0], q_src.shape[0]
+    nb = () if qblock is None else (-(-x.shape[-1] // int(qblock)),)
+    if (x.ndim != 2 or q_self.shape != x.shape
+            or q_src.shape[1:] != x.shape[1:]
+            or idx.ndim != 2 or idx.shape[0] != K
+            or sigmas.shape != idx.shape
+            or s_self.shape != (K,) + nb or s_src.shape != (M,) + nb):
         raise ValueError(
-            f"bad shapes {x.shape} {q_self.shape} {q_neighbors.shape} "
-            f"{s_neighbors.shape} {sigmas.shape}")
-    if qblock is not None:
-        nb = -(-x.shape[0] // int(qblock))
-        if s_self.shape != (nb,) or s_neighbors.shape[1:] != (nb,):
-            raise ValueError(
-                f"qblock={qblock} wants {nb} scales per model, got "
-                f"{s_self.shape} {s_neighbors.shape}")
+            f"bad shapes {x.shape} {q_self.shape} {q_src.shape} "
+            f"{idx.shape} {sigmas.shape}; scales {s_self.shape} "
+            f"{s_src.shape} (qblock={qblock} wants {nb or 'one'} per "
+            f"model)")
     if impl == "xla":
-        return _ref.quant_consensus_update_reference(
-            x, q_self, s_self, q_neighbors, s_neighbors, sigmas,
-            qblock=qblock)
-    return _qc.quant_consensus_update(
-        x, q_self, s_self, q_neighbors, s_neighbors, sigmas,
-        block_n=block_n, interpret=(impl == "interpret"), qblock=qblock)
+        return jax.vmap(functools.partial(
+            _ref.quant_consensus_update_reference, qblock=qblock))(
+            x, q_self, s_self, q_src[idx], s_src[idx], sigmas)
+    kernel = functools.partial(
+        _qc.quant_consensus_update, block_n=block_n,
+        interpret=(impl == "interpret"), qblock=qblock)
+    return _fold_agents(kernel, 3, 2)(
+        x, q_self, s_self, q_src, s_src, idx, sigmas)

@@ -24,7 +24,12 @@ def make_agent_mesh(positions: int = 0, axis_name: str = "agents"):
     """1-D mesh whose axis carries consensus AGENTS (one agent per
     position for the engine's ``distributed`` plan; a block of agents
     per position for ``sharded``). ``positions`` 0 ⇒ all local devices;
-    values above the device count are clamped."""
+    asking for more positions than there are devices is an error."""
     n = len(jax.devices())
-    positions = n if positions <= 0 else min(positions, n)
-    return jax.make_mesh((positions,), (axis_name,))
+    if positions > n:
+        raise ValueError(
+            f"make_agent_mesh(positions={positions}) needs {positions} "
+            f"devices but {n} {jax.default_backend()} device(s) are "
+            f"visible; use positions<={n} (0 takes all of them)")
+    return jax.make_mesh((positions if positions > 0 else n,),
+                         (axis_name,))

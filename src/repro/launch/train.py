@@ -33,6 +33,7 @@ from repro.core import topology as topo_lib
 from repro.core.engine import (PLAN_KINDS, AsyncState, ConsensusEngine,
                                where_active)
 from repro.data import TaskTokenDistribution
+from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
 from repro.models import frontend
 from repro.models.api import get_model, lm_loss
@@ -375,6 +376,7 @@ def main():
                          "Eq.-11 joules by link class, wire bits, "
                          "disagreement — see repro.telemetry.schema)")
     args = ap.parse_args()
+    compile_cache.enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

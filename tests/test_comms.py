@@ -405,30 +405,33 @@ def test_auto_path_accounts_for_codec_payload():
 def test_quant_consensus_kernel_parity():
     """ops.quant_consensus_update interpret (Pallas body) == XLA oracle."""
     rng = np.random.default_rng(0)
-    N, H = 1000, 3
-    x = jnp.asarray(rng.normal(size=N), jnp.float32)
-    qs = jnp.asarray(rng.integers(-127, 128, N), jnp.int8)
-    ss = jnp.float32(0.01)
-    qn = jnp.asarray(rng.integers(-127, 128, (H, N)), jnp.int8)
-    sn = jnp.asarray(rng.uniform(0.005, 0.02, H), jnp.float32)
-    sig = jnp.asarray(rng.uniform(0.0, 0.3, H), jnp.float32)
-    a = ops.quant_consensus_update(x, qs, ss, qn, sn, sig, impl="xla")
-    b = ops.quant_consensus_update(x, qs, ss, qn, sn, sig,
+    K, N, H, M = 2, 1000, 3, 4
+    x = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    qs = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
+    ss = jnp.asarray([0.01, 0.02], jnp.float32)
+    qn = jnp.asarray(rng.integers(-127, 128, (M, N)), jnp.int8)
+    sn = jnp.asarray(rng.uniform(0.005, 0.02, M), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, M, (K, H)), jnp.int32)
+    sig = jnp.asarray(rng.uniform(0.0, 0.3, (K, H)), jnp.float32)
+    a = ops.quant_consensus_update(x, qs, ss, qn, sn, idx, sig, impl="xla")
+    b = ops.quant_consensus_update(x, qs, ss, qn, sn, idx, sig,
                                    impl="interpret", block_n=256)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-6, atol=1e-6)
 
 
 def test_quant_consensus_kernel_guards():
-    x = jnp.zeros(8, jnp.float32)
-    q = jnp.zeros(8, jnp.int8)
+    x = jnp.zeros((1, 8), jnp.float32)
+    q = jnp.zeros((1, 8), jnp.int8)
     qn = jnp.zeros((2, 8), jnp.int8)
     s = jnp.ones(2, jnp.float32)
+    idx = jnp.zeros((1, 2), jnp.int32)
     with pytest.raises(TypeError):        # wire must be int8
-        ops.quant_consensus_update(x, x, jnp.float32(1), qn, s, s)
+        ops.quant_consensus_update(x, x, jnp.ones(1), qn, s, idx,
+                                   jnp.ones((1, 2)))
     with pytest.raises(ValueError):       # mismatched neighbour count
-        ops.quant_consensus_update(x, q, jnp.float32(1), qn, s,
-                                   jnp.ones(3))
+        ops.quant_consensus_update(x, q, jnp.ones(1), qn, s, idx,
+                                   jnp.ones((1, 3)))
 
 
 def test_quant_consensus_parity_at_k256():
@@ -454,32 +457,35 @@ def test_blockwise_quant_consensus_kernel_parity():
     including a tensor length that is not a multiple of the scale block
     or the kernel tile."""
     rng = np.random.default_rng(1)
-    N, H, B = 300, 3, 64                  # 300 = 4 full blocks + 44 tail
+    K, N, H, B, M = 2, 300, 3, 64, 4      # 300 = 4 full blocks + 44 tail
     nb = -(-N // B)
-    x = jnp.asarray(rng.normal(size=N), jnp.float32)
-    qs = jnp.asarray(rng.integers(-127, 128, N), jnp.int8)
-    ss = jnp.asarray(rng.uniform(0.005, 0.02, nb), jnp.float32)
-    qn = jnp.asarray(rng.integers(-127, 128, (H, N)), jnp.int8)
-    sn = jnp.asarray(rng.uniform(0.005, 0.02, (H, nb)), jnp.float32)
-    sig = jnp.asarray(rng.uniform(0.0, 0.3, H), jnp.float32)
-    a = ops.quant_consensus_update(x, qs, ss, qn, sn, sig, impl="xla",
+    x = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    qs = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
+    ss = jnp.asarray(rng.uniform(0.005, 0.02, (K, nb)), jnp.float32)
+    qn = jnp.asarray(rng.integers(-127, 128, (M, N)), jnp.int8)
+    sn = jnp.asarray(rng.uniform(0.005, 0.02, (M, nb)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, M, (K, H)), jnp.int32)
+    sig = jnp.asarray(rng.uniform(0.0, 0.3, (K, H)), jnp.float32)
+    a = ops.quant_consensus_update(x, qs, ss, qn, sn, idx, sig, impl="xla",
                                    qblock=B)
-    b = ops.quant_consensus_update(x, qs, ss, qn, sn, sig,
+    b = ops.quant_consensus_update(x, qs, ss, qn, sn, idx, sig,
                                    impl="interpret", qblock=B, block_n=128)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-6, atol=1e-6)
     # manual decode (the codec's own blocking) then the plain Eq.-6 mix
     codec = comms.IntCodec(8, block=B)
     like = jax.ShapeDtypeStruct((N,), jnp.float32)
-    xhat = codec.decode_leaf({"q": qs, "scale": ss}, like)
-    nbs = jnp.stack([codec.decode_leaf({"q": qn[h], "scale": sn[h]}, like)
-                     for h in range(H)])
-    want = x + jnp.einsum("h,hn->n", sig, nbs - xhat[None])
-    np.testing.assert_allclose(np.asarray(a), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
+    for k in range(K):
+        xhat = codec.decode_leaf({"q": qs[k], "scale": ss[k]}, like)
+        nbs = jnp.stack([codec.decode_leaf({"q": qn[j], "scale": sn[j]},
+                                           like) for j in idx[k]])
+        want = x[k] + jnp.einsum("h,hn->n", sig[k], nbs - xhat[None])
+        np.testing.assert_allclose(np.asarray(a[k]), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
     # scale-count guard
     with pytest.raises(ValueError):
-        ops.quant_consensus_update(x, qs, ss[:-1], qn, sn, sig, qblock=B)
+        ops.quant_consensus_update(x, qs, ss[:, :-1], qn, sn, idx, sig,
+                                   qblock=B)
 
 
 def test_sharded_blockwise_int8_stays_fused_parity_at_k256():

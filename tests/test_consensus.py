@@ -148,8 +148,10 @@ def test_kernel_consensus_matches_dense(rng_key):
     flat = jnp.concatenate([s["w"][0].ravel(), s["b"][0].ravel()])
     nb = jnp.stack([jnp.concatenate([s["w"][h].ravel(), s["b"][h].ravel()])
                     for h in range(1, K)])
-    out = ops.consensus_update(flat, nb, jnp.asarray(M)[0, 1:],
-                               impl="interpret", block_n=64)
+    out = ops.consensus_update(flat[None], nb,
+                               jnp.arange(K - 1)[None],
+                               jnp.asarray(M)[0:1, 1:],
+                               impl="interpret", block_n=64)[0]
     want = jnp.concatenate([dense["w"][0].ravel(), dense["b"][0].ravel()])
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

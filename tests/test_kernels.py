@@ -76,14 +76,51 @@ def test_rglru_scan_vs_oracle(B, T, W, bt, bw, dtype, rng_key):
     (1, 128, 64), (2, 1000, 256), (4, 70000, 8192),
 ])
 def test_consensus_update_vs_oracle(H, N, bn, dtype, rng_key):
-    ks = jax.random.split(rng_key, 3)
-    x = _rand(ks[0], (N,), dtype)
-    nb = _rand(ks[1], (H, N), dtype)
-    sig = jax.nn.softmax(jax.random.normal(ks[2], (H,))) * 0.7
-    y = ops.consensus_update(x, nb, sig, impl="interpret", block_n=bn)
-    want = ref.consensus_update_reference(x, nb, sig)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(want, np.float32), **TOL[dtype])
+    K, M = 3, 5                          # agents, source rows
+    ks = jax.random.split(rng_key, 4)
+    x = _rand(ks[0], (K, N), dtype)
+    src = _rand(ks[1], (M, N), dtype)
+    idx = jax.random.randint(ks[2], (K, H), 0, M)
+    sig = jax.nn.softmax(jax.random.normal(ks[3], (K, H)), axis=1) * 0.7
+    y = ops.consensus_update(x, src, idx, sig, impl="interpret",
+                             block_n=bn)
+    for k in range(K):
+        want = ref.consensus_update_reference(x[k], src[idx[k]], sig[k])
+        np.testing.assert_allclose(np.asarray(y[k], np.float32),
+                                   np.asarray(want, np.float32),
+                                   **TOL[dtype])
+
+
+@pytest.mark.parametrize("shared_src", [False, True])
+def test_consensus_kernels_fold_an_outer_vmap(shared_src, rng_key):
+    """vmap over agent blocks (the sharded plan's one-device emulation)
+    folds into the kernels' agent axis: same result as the oracle per
+    block, whether each block reads its own source or a shared one."""
+    B, K, M, N, H = 3, 2, 4, 300, 2
+    ks = jax.random.split(rng_key, 4)
+    x = _rand(ks[0], (B, K, N), jnp.float32)
+    src = _rand(ks[1], (M, N) if shared_src else (B, M, N), jnp.float32)
+    idx = jax.random.randint(ks[2], (B, K, H), 0, M)
+    sig = jax.random.uniform(ks[3], (B, K, H), maxval=0.4)
+    src_axis = None if shared_src else 0
+    got, want = (jax.vmap(
+        lambda x, s, i, g, impl=impl: ops.consensus_update(
+            x, s, i, g, impl=impl, block_n=128),
+        in_axes=(0, src_axis, 0, 0))(x, src, idx, sig)
+        for impl in ("interpret", "xla"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    q = jnp.round(x * 40).astype(jnp.int8)
+    qs = jnp.round(src * 40).astype(jnp.int8)
+    s = jnp.full((B, K, -(-N // 64)), 0.025)
+    ss = jnp.full(qs.shape[:-1] + (-(-N // 64),), 0.025)
+    got, want = (jax.vmap(
+        lambda x, q, s, qs, ss, i, g, impl=impl: ops.quant_consensus_update(
+            x, q, s, qs, ss, i, g, impl=impl, block_n=128, qblock=64),
+        in_axes=(0, 0, 0, src_axis, src_axis, 0, 0))(
+        x, q, s, qs, ss, idx, sig) for impl in ("interpret", "xla"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_ops_shape_guards(rng_key):
@@ -92,5 +129,9 @@ def test_ops_shape_guards(rng_key):
     with pytest.raises(ValueError):
         ops.flash_attention(q, k, k)
     with pytest.raises(TypeError):
-        ops.consensus_update(jnp.zeros(4, jnp.int32), jnp.zeros((1, 4)),
-                             jnp.ones(1))
+        ops.consensus_update(jnp.zeros((1, 4), jnp.int32),
+                             jnp.zeros((1, 4)), jnp.zeros((1, 1), int),
+                             jnp.ones((1, 1)))
+    with pytest.raises(ValueError):      # one index row per agent
+        ops.consensus_update(jnp.zeros((2, 4)), jnp.zeros((3, 4)),
+                             jnp.zeros((3, 1), int), jnp.ones((3, 1)))

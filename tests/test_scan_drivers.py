@@ -342,3 +342,26 @@ def test_first_hit():
     assert scanloop.first_hit([False, False, True, True]) == 2
     assert scanloop.first_hit([True]) == 0
     assert scanloop.first_hit([False, False]) is None
+
+
+def test_traceable_propagates_errors_that_are_not_tracing_errors():
+    """Only a tracer-concretization failure means 'use a host callback';
+    any other exception is a bug in the sampler and must surface."""
+    def broken(k, t):
+        raise ValueError("bad batch shape")
+
+    with pytest.raises(ValueError, match="bad batch shape"):
+        scanloop.traceable(broken, jax.random.PRNGKey(0), jnp.int32(0))
+
+
+def test_traceable_logs_its_host_fallback_once(caplog):
+    def host_fn(k, t):
+        return np.float32(int(t)) * np.ones(2, np.float32)
+
+    with caplog.at_level("WARNING", logger="repro.core.scanloop"):
+        for _ in range(2):
+            _, traced = scanloop.traceable(host_fn, jax.random.PRNGKey(0),
+                                           jnp.int32(0), name="probe-once")
+            assert not traced
+    hits = [r for r in caplog.records if "probe-once" in r.getMessage()]
+    assert len(hits) == 1 and "host callback" in hits[0].getMessage()
