@@ -30,9 +30,9 @@ Phases (one chip, no arguments):
 Every phase checks its own result (finite losses and params, no host
 callback in any compiled round program, the Pallas kernel present in the
 sparse programs, parity within ``PARITY_TOL``) and any failure ends the
-run with a non-zero exit. The times printed are smoke output — wall
-seconds of a cold run — not benchmark measurements. The last line of
-standard output is one JSON object naming the device.
+run with a non-zero exit. It times nothing: ``bench/run.py`` measures.
+The last line of standard output is one JSON object naming the
+device.
 
     python chip_smoke.py               # one chip
     python chip_smoke.py --four-chips  # four chips
@@ -43,7 +43,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
@@ -65,26 +64,6 @@ def say(msg: str):
 def check(ok, msg: str):
     if not ok:
         raise RuntimeError(f"smoke check failed: {msg}")
-
-
-class CompileClock:
-    """Sums XLA backend compile seconds while active."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def _listen(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-
-    def __enter__(self):
-        jax.monitoring.register_event_duration_secs_listener(self._listen)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_duration_listener(self._listen)
 
 
 def all_finite(tree) -> bool:
@@ -131,23 +110,13 @@ def phase_casestudy(cfg, *, plan: str, codec, want_plan: str, t0: int = 16,
     from repro.rl.casestudy import CaseStudy
     name = f"casestudy plan={plan} codec={codec}"
     kmeta, kfl = jax.random.split(jax.random.PRNGKey(SEED))
-    with scanloop.retained_programs() as records, CompileClock() as clock:
+    with scanloop.retained_programs() as records:
         cs = CaseStudy(cfg=cfg, plan=plan, codec=codec, chunk=chunk)
         check(cs.engine.plan.kind == want_plan,
               f"{name} resolved {cs.engine.plan.kind}, expected {want_plan}")
-        t = time.perf_counter()
         params, meta_hist = cs.meta_train(kmeta, t0)
         stacked, t_i, rewards = cs.adapt_task(kfl, 0, params,
                                               max_rounds=rounds)
-        jax.block_until_ready(stacked)
-        cold = time.perf_counter() - t
-        t = time.perf_counter()
-        cs.meta_train(kmeta, t0)
-        meta_s = (time.perf_counter() - t) / t0
-        t = time.perf_counter()
-        again, t_again, _ = cs.adapt_task(kfl, 0, params, max_rounds=rounds)
-        jax.block_until_ready(again)
-        fl_s = (time.perf_counter() - t) / t_again
     check(len(meta_hist) == t0 and all(np.isfinite(meta_hist)),
           f"{name}: meta losses {meta_hist}")
     check(rewards and all(np.isfinite(rewards)), f"{name}: rewards {rewards}")
@@ -156,9 +125,7 @@ def phase_casestudy(cfg, *, plan: str, codec, want_plan: str, t0: int = 16,
     n = audit_programs(records, kernel=want_plan == "sparse-pallas")
     say(f"{name}: plan {cs.engine.plan.kind}, K={cs.engine.K} per task, "
         f"meta loss {meta_hist[0]:.6g} -> {meta_hist[-1]:.6g}, "
-        f"t_i {t_i} of {rounds} rounds, reward {rewards[-1]:.6g}")
-    say(f"{name}: cold {cold:.3f} s ({clock.seconds:.3f} s compiling), "
-        f"warm {meta_s:.6f} s/meta round, {fl_s:.6f} s/FL round, "
+        f"t_i {t_i} of {rounds} rounds, reward {rewards[-1]:.6g}, "
         f"{n} programs audited, peak {peak_gb():.3f} GB")
 
 
@@ -167,24 +134,18 @@ def phase_federated(cfg, *, agents: int = 4, rounds: int = 2,
     from repro.core import scanloop
     from repro.launch.train import train_federated
     name = f"federated {cfg.name} K={agents}"
-    with scanloop.retained_programs() as records, CompileClock() as clock:
-        t = time.perf_counter()
+    with scanloop.retained_programs() as records:
         stacked, hist, _ = train_federated(
             cfg, rounds=rounds, agents=agents, tasks=1, local_steps=1,
             batch=1, seq=seq, lr=1e-3, consensus_plan="sparse-pallas",
             codec="int8:b64", chunk=chunk)
-        jax.block_until_ready(stacked)
-        wall = time.perf_counter() - t
     check(len(hist) == rounds and all(np.isfinite(hist)),
           f"{name}: losses {hist}")
     check(all_finite(stacked), f"{name}: non-finite params")
     n_params = sum(x.size for x in jax.tree.leaves(stacked)) // agents
     n = audit_programs(records, kernel=True)
     say(f"{name}: {n_params} params/agent, losses "
-        f"{', '.join(f'{l:.6g}' for l in hist)}")
-    say(f"{name}: {wall:.3f} s for {rounds} rounds incl. "
-        f"{clock.seconds:.3f} s compiling "
-        f"({(wall - clock.seconds) / rounds:.3f} s/round besides), "
+        f"{', '.join(f'{l:.6g}' for l in hist)}, "
         f"{n} programs audited, peak {peak_gb():.3f} GB")
 
 
@@ -251,10 +212,8 @@ def phase_mesh(cfg):
               f"{plan}: {on_mesh.plan}")
         placed = jax.device_put(
             stacked, NamedSharding(mesh, PartitionSpec("agents")))
-        t = time.perf_counter()
         compiled = jax.jit(lambda p: on_mesh.step(p)[0]).lower(
             placed).compile()
-        compile_s = time.perf_counter() - t
         wire = PLAN_AUDIT_EXPECTATIONS[plan]["wire_collective"]
         check(wire in compiled.as_text(), f"{plan}: no {wire} in the HLO")
         got = compiled(placed)
@@ -270,7 +229,7 @@ def phase_mesh(cfg):
         say(f"mesh {plan} K={K} int8:b64: {wire} present, output on "
             f"{len(jax.tree.leaves(got)[0].sharding.device_set)} devices, "
             f"max |mesh - one device| = {err:.3e} (tolerance "
-            f"{PARITY_TOL:.0e}), compile {compile_s:.3f} s")
+            f"{PARITY_TOL:.0e})")
         check(err <= PARITY_TOL, f"{plan}: mesh vs one device error {err}")
 
 
@@ -291,7 +250,6 @@ def main(argv=None) -> int:
     say(f"compile cache {enable_compile_cache()}")
     say(f"device {dev.platform} {dev.device_kind} x{len(jax.devices())}")
     dqn = get_arch("paper-dqn")
-    t = time.perf_counter()
     if args.four_chips:
         phase_mesh(dqn)
     else:
@@ -300,7 +258,7 @@ def main(argv=None) -> int:
                         want_plan="sparse-pallas")
         phase_federated(get_arch("xlstm-125m"))
         phase_parity(dqn)
-    say(f"all phases passed in {time.perf_counter() - t:.3f} s")
+    say("all phases passed")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

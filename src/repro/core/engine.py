@@ -802,6 +802,7 @@ class ConsensusEngine:
         return (self.gamma * sig).T
 
     # -- the round ----------------------------------------------------------
+    @jax.named_scope("eq6_mix")
     def step(self, stacked_params, codec_state=None, key=None, *, mix=None,
              t=None, mask=None, survival=None):
         """One Eq.-(6) consensus round on agent-stacked params (leading

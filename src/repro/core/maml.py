@@ -57,6 +57,7 @@ def inner_adapt(loss_fn: Callable, params, batch, lr: float,
     return params
 
 
+@jax.named_scope("maml_step")
 def maml_meta_step(loss_fn: Callable, meta_params, support, query, *,
                    inner_lr: float, outer_lr: float,
                    inner_steps: int = 1, first_order: bool = True,

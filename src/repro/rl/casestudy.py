@@ -40,6 +40,7 @@ from repro.core.protocol import ProtocolResult
 from repro.models import dqn as qmodel
 from repro.rl import dqn as dqnrl
 from repro.rl import gridworld as gw
+from repro.telemetry import spans
 
 META_TASKS = (0, 1, 5)        # {τ1, τ2, τ6} of Fig. 2(c)
 R_TARGET = 100.0              # running-reward target (paper: R = 50 in its
@@ -75,6 +76,7 @@ def sample_td_batches(key, task_id: int, n_batches: int, *,
     return jax.tree.map(lambda x: x[idx], data)
 
 
+@jax.named_scope("episodes")
 def sample_episode_batches(key, params, cfg, task_id: int, n_batches: int,
                            *, batch_size: int = 16, epsilon: float = 0.1,
                            episodes: int = 1):
@@ -90,6 +92,7 @@ def sample_episode_batches(key, params, cfg, task_id: int, n_batches: int,
     return jax.tree.map(lambda x: x[idx], flat)
 
 
+@jax.named_scope("local_sgd")
 def _clipped_sgd_steps(loss_fn, params, batches, lr: float,
                        clip: float = 5.0):
     def one(p, b):
@@ -408,19 +411,25 @@ class CaseStudy:
     def meta_train(self, key, t0: int):
         """Stage 1: t0 meta rounds, ``self.chunk`` rounds per compiled
         program, meta-loss history synced once per chunk."""
-        kinit, kdata = jax.random.split(key)
-        # own(): _meta_chunk donates its params carry on donating backends
-        params = scanloop.own(self.init_params(kinit))
-        hist = []
-        for start in range(0, t0, self.chunk):
-            n = min(self.chunk, t0 - start)
-            ts = jnp.arange(start, start + n, dtype=jnp.int32)
-            (params, kdata), losses = self._meta_chunk(params, kdata, ts)
-            if self.telemetry is not None:
-                self.telemetry.record_maml_rounds(
-                    {"meta_loss": losses}, start)
-            hist.extend(float(x) for x in np.asarray(losses))
-        return params, hist
+        with spans.span("driver.meta_train"):
+            kinit, kdata = jax.random.split(key)
+            # own(): _meta_chunk donates its params carry on donating
+            # backends
+            params = scanloop.own(self.init_params(kinit))
+            hist = []
+            for start in range(0, t0, self.chunk):
+                n = min(self.chunk, t0 - start)
+                with spans.span("driver.dispatch"):
+                    ts = jnp.arange(start, start + n, dtype=jnp.int32)
+                    (params, kdata), losses = self._meta_chunk(
+                        params, kdata, ts)
+                with spans.span("driver.sync"):
+                    jax.block_until_ready(losses)
+                if self.telemetry is not None:
+                    self.telemetry.record_maml_rounds(
+                        {"meta_loss": losses}, start)
+                hist.extend(float(x) for x in np.asarray(losses))
+            return params, hist
 
     def adapt_task(self, key, task_id: int, init_params, *,
                    max_rounds: int = 400):
@@ -440,45 +449,59 @@ class CaseStudy:
         the ``rounds_used`` rounds actually executed — frozen tail
         rounds (target hit mid-chunk, or chunk ∤ max_rounds) bill
         zero."""
-        C = self.network.devices_per_cluster
-        # own(): _fl_chunks donate the stacked/EF carries; the broadcast
-        # must not alias the caller's init_params on donating backends
-        stacked = scanloop.own(jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (C,) + x.shape),
-            init_params))
-        codec_state = (self.codec.init_state(stacked)
-                       if self.codec is not None and self.codec.stateful
-                       else None)
-        hist = []
-        rounds = max_rounds
-        reached = jnp.asarray(False)
-        step = self._fl_chunks[task_id]
-        limit = jnp.int32(max_rounds)
-        eng = self._engines[task_id]
-        astate = (eng.init_async_state() if eng.agents is not None
-                  else None)
-        for start in range(0, max_rounds, self.chunk):
-            ts = jnp.arange(start, start + self.chunk, dtype=jnp.int32)
-            (stacked, codec_state, key, reached, astate), ys = step(
-                stacked, codec_state, key, reached, ts, limit, astate)
-            hits, live_mask, Rs = (np.asarray(y) for y in ys[:3])  # ONE sync
-            if self.telemetry is not None:
-                self.telemetry.record_rounds(
-                    self._recorders[task_id], ys[3], start, driver="fl",
-                    extra={"task_id": task_id})
-            hist.extend(float(r) for r, v in zip(Rs, live_mask) if v)
-            h = scanloop.first_hit(hits)
-            if h is not None:
-                rounds = start + h + 1
-                break
-        # Eq.-(11) bill over EXACTLY the rounds_used executed rounds:
-        # static lockstep runs price rounds × the full graph; dropout
-        # and/or availability runs replay the host streams
-        # (bit-identical to the in-scan masks by the shared fold-in
-        # convention) and price each round's DELIVERED wires only — a
-        # wire bills iff its link survived AND both endpoints were
-        # awake, matching ``AsyncRound.delivered`` and the telemetry
-        # stream exactly (left-to-right float64 sum, same expression)
+        with spans.span("driver.adapt_task", task_id=task_id):
+            with spans.span("driver.setup"):
+                C = self.network.devices_per_cluster
+                # own(): _fl_chunks donate the stacked/EF carries; the
+                # broadcast must not alias the caller's init_params on
+                # donating backends
+                stacked = scanloop.own(jax.tree.map(
+                    lambda x: jnp.broadcast_to(x[None], (C,) + x.shape),
+                    init_params))
+                codec_state = (
+                    self.codec.init_state(stacked)
+                    if self.codec is not None and self.codec.stateful
+                    else None)
+                hist = []
+                rounds = max_rounds
+                reached = jnp.asarray(False)
+                step = self._fl_chunks[task_id]
+                limit = jnp.int32(max_rounds)
+                eng = self._engines[task_id]
+                astate = (eng.init_async_state() if eng.agents is not None
+                          else None)
+            for start in range(0, max_rounds, self.chunk):
+                with spans.span("driver.dispatch"):
+                    ts = jnp.arange(start, start + self.chunk,
+                                    dtype=jnp.int32)
+                    (stacked, codec_state, key, reached, astate), ys = step(
+                        stacked, codec_state, key, reached, ts, limit,
+                        astate)
+                with spans.span("driver.sync"):
+                    hits, live_mask, Rs = (np.asarray(y) for y in ys[:3])
+                if self.telemetry is not None:
+                    self.telemetry.record_rounds(
+                        self._recorders[task_id], ys[3], start, driver="fl",
+                        extra={"task_id": task_id})
+                hist.extend(float(r) for r, v in zip(Rs, live_mask) if v)
+                h = scanloop.first_hit(hits)
+                if h is not None:
+                    rounds = start + h + 1
+                    break
+            with spans.span("driver.bill"):
+                self.last_adapt_comm_joules = self._comm_joules(
+                    task_id, rounds)
+            return stacked, rounds, hist
+
+    def _comm_joules(self, task_id: int, rounds: int) -> float:
+        """Eq.-(11) bill over EXACTLY the ``rounds`` executed rounds:
+        static lockstep runs price rounds × the full graph; dropout
+        and/or availability runs replay the host streams (bit-identical
+        to the in-scan masks by the shared fold-in convention) and price
+        each round's DELIVERED wires only — a wire bills iff its link
+        survived AND both endpoints were awake, matching
+        ``AsyncRound.delivered`` and the telemetry stream exactly
+        (left-to-right float64 sum, same expression)."""
         proc = self._agent_process(task_id)
         if self.dropout_p > 0 or proc is not None:
             base = self.cluster_topology
@@ -497,24 +520,22 @@ class CaseStudy:
                              topo_lib.NONE))
                 total += billed.round_comm_joules(
                     self.energy_params, codec=self.codec)
-            self.last_adapt_comm_joules = float(total)
-        else:
-            self.last_adapt_comm_joules = rounds * float(
-                self.cluster_topology.round_comm_joules(
-                    self.energy_params, codec=self.codec))
-        return stacked, rounds, hist
+            return float(total)
+        return rounds * float(self.cluster_topology.round_comm_joules(
+            self.energy_params, codec=self.codec))
 
     def run(self, key, t0: int, *, max_rounds: int = 400) -> ProtocolResult:
-        kmeta, kfl = jax.random.split(key)
-        meta_params, meta_hist = self.meta_train(kmeta, t0)
-        rounds, hists, comm = [], [], []
-        for tid in range(self.network.num_tasks):
-            kfl, kt = jax.random.split(kfl)
-            _, t_i, h = self.adapt_task(kt, tid, meta_params,
-                                        max_rounds=max_rounds)
-            rounds.append(t_i)
-            hists.append(h)
-            comm.append(self.last_adapt_comm_joules)
+        with spans.span("driver.process"):
+            kmeta, kfl = jax.random.split(key)
+            meta_params, meta_hist = self.meta_train(kmeta, t0)
+            rounds, hists, comm = [], [], []
+            for tid in range(self.network.num_tasks):
+                kfl, kt = jax.random.split(kfl)
+                _, t_i, h = self.adapt_task(kt, tid, meta_params,
+                                            max_rounds=max_rounds)
+                rounds.append(t_i)
+                hists.append(h)
+                comm.append(self.last_adapt_comm_joules)
         return ProtocolResult(
             t0=t0, rounds_per_task=rounds, meta_history=meta_hist,
             fl_histories=hists, energy_params=self.energy_params,

@@ -95,6 +95,7 @@ def experience_batches(key, params, cfg, task_id: int, n_batches: int,
     return out
 
 
+@jax.named_scope("greedy_eval")
 def evaluate(key, params, cfg, task_id: int, *, episodes: int = 4,
              steps: int = 20):
     """Mean greedy running reward R (paper's accuracy target R = 50)."""

@@ -32,7 +32,7 @@ callback only observes.
 
 Sinks (:mod:`~repro.telemetry.sinks`) are pluggable: in-memory for
 tests, JSONL event log (schema-checked by
-``python -m repro.telemetry.schema``), console. ``report()`` adds the
+``python -m repro.telemetry.schema``). ``report()`` adds the
 harness counters — ``scanloop.TRACE_COUNTS``, program-cache
 hits/misses/evictions, per-``ProgramRecord`` donation flags — so one
 call answers both "what did each round cost?" and "did the sweep
@@ -50,13 +50,14 @@ from repro.telemetry.buffer import (MetricBuffer, RoundRecorder,
                                     consensus_disagreement, ROW_FIELDS)
 from repro.telemetry.report import harness_report
 from repro.telemetry.schema import validate_event, validate_jsonl
-from repro.telemetry.sinks import ConsoleSink, JsonlSink, MemorySink
+from repro.telemetry.sinks import JsonlSink, MemorySink
+from repro.telemetry import spans
 
 __all__ = [
     "Telemetry", "MetricBuffer", "RoundRecorder", "ROW_FIELDS",
     "consensus_disagreement", "harness_report",
     "validate_event", "validate_jsonl",
-    "MemorySink", "JsonlSink", "ConsoleSink",
+    "MemorySink", "JsonlSink",
 ]
 
 MODES = ("buffered", "streaming")
@@ -130,13 +131,16 @@ class Telemetry:
                 "jit (they are tracers, not values) — run the driver "
                 "outside jit, or use streaming mode, whose "
                 "jax.debug.callback emits from inside the trace")
-        events = recorder.finalize(rows, int(start), driver=driver,
-                                   extra=extra)
-        self.buffer.extend(events)
-        if not self.streaming:
-            for e in events:
-                if e["live"]:
-                    self._emit(e)
+        with spans.span("telemetry.fetch"):
+            host = recorder.fetch(rows)
+        with spans.span("telemetry.price"):
+            events = recorder.finalize(host, int(start), driver=driver,
+                                       extra=extra)
+            self.buffer.extend(events)
+            if not self.streaming:
+                for e in events:
+                    if e["live"]:
+                        self._emit(e)
         return events
 
     def record_maml_rounds(self, metrics, start,
@@ -150,23 +154,25 @@ class Telemetry:
             raise ValueError(
                 "buffered telemetry cannot ingest meta metrics under an "
                 "outer jit — use streaming mode")
-        loss = np.asarray(metrics["meta_loss"])
-        gn = metrics.get("meta_grad_norm")
-        gn = None if gn is None else np.asarray(gn)
-        events = []
-        for i in range(loss.shape[0]):
-            e = {"type": "round", "driver": "maml",
-                 "round": int(start) + i, "live": True,
-                 "meta_loss": float(loss[i])}
-            if gn is not None:
-                e["meta_grad_norm"] = float(gn[i])
-            if extra:
-                e.update(extra)
-            events.append(e)
-        self.buffer.extend(events)
-        if not self.streaming:
-            for e in events:
-                self._emit(e)
+        with spans.span("telemetry.fetch"):
+            loss = np.asarray(metrics["meta_loss"])
+            gn = metrics.get("meta_grad_norm")
+            gn = None if gn is None else np.asarray(gn)
+        with spans.span("telemetry.price"):
+            events = []
+            for i in range(loss.shape[0]):
+                e = {"type": "round", "driver": "maml",
+                     "round": int(start) + i, "live": True,
+                     "meta_loss": float(loss[i])}
+                if gn is not None:
+                    e["meta_grad_norm"] = float(gn[i])
+                if extra:
+                    e.update(extra)
+                events.append(e)
+            self.buffer.extend(events)
+            if not self.streaming:
+                for e in events:
+                    self._emit(e)
         return events
 
     # -- streaming callbacks (called from INSIDE the chunk) -------------

@@ -169,6 +169,7 @@ class RoundRecorder:
             jnp.asarray(self._sender_index)].add(
             jnp.asarray(hit, jnp.int32))
 
+    @jax.named_scope("telemetry_row")
     def row(self, stacked, survival, *, metric, reached, live,
             active=None, age=None):
         """One live round's row. ``survival`` is the PLAN-SHAPED
@@ -259,11 +260,16 @@ class RoundRecorder:
                         + int(a_ul) / p.E_UL + int(a_dl) / p.E_DL)
                 for a_sl, a_ul, a_dl in zip(agent_sl, agent_ul, agent_dl)]
 
-    def finalize(self, rows, start: int, driver: str = "fl",
+    @staticmethod
+    def fetch(rows) -> dict:
+        """Stacked chunk rows (device or numpy) → numpy arrays, one
+        device→host copy per field."""
+        return {k: np.asarray(v) for k, v in rows.items()}
+
+    def finalize(self, host, start: int, driver: str = "fl",
                  extra: Optional[dict] = None):
-        """Stacked chunk rows (device or numpy, leading axis = rounds)
-        → list of host event dicts, one per round, priced in float64."""
-        host = {k: np.asarray(v) for k, v in rows.items()}
+        """Host rows (:meth:`fetch`, leading axis = rounds) → list of
+        host event dicts, one per round, priced in float64."""
         n = host["live"].shape[0]
         base = {"type": "round", "driver": driver,
                 "plan": self.engine.plan.kind,

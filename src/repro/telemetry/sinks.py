@@ -10,8 +10,6 @@ filtered before sinks see anything.
 from __future__ import annotations
 
 import json
-import sys
-from typing import Optional
 
 
 class MemorySink:
@@ -50,30 +48,3 @@ class JsonlSink:
         if self._fh is not None:
             self._fh.close()
             self._fh = None
-
-
-class ConsoleSink:
-    """Compact per-round lines on a stream (default stderr, keeping
-    stdout clean for driver output)."""
-
-    def __init__(self, stream=None, every: int = 1):
-        self.stream = stream if stream is not None else sys.stderr
-        self.every = max(1, int(every))
-        self._n = 0
-
-    def emit(self, event: dict):
-        self._n += 1
-        if (self._n - 1) % self.every:
-            return
-        d = event.get("driver", "?")
-        t = event.get("round", "?")
-        if d == "maml":
-            body = f"meta_loss={event.get('meta_loss', float('nan')):.6g}"
-        else:
-            body = (f"J={event.get('joules', 0.0):.4g}"
-                    f" edges={event.get('edges', 0)}"
-                    f" disagreement={event.get('disagreement', 0.0):.4g}")
-        print(f"[telemetry] {d} round={t} {body}", file=self.stream)
-
-    def close(self):
-        pass
