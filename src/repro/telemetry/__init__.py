@@ -10,10 +10,14 @@ sharp contract:
 
 **buffered** (default) — stays PURE. Each round's metrics ride the scan
 outputs as one fixed-shape row (:class:`~repro.telemetry.buffer
-.RoundRecorder`); the whole per-round buffer reaches the host in the
-single sync the driver already pays at the chunk boundary, where it is
-priced (Eq.-11 joules by UL/DL/SL class, wire bits) in float64 and
-appended to the :class:`~repro.telemetry.buffer.MetricBuffer` and sinks.
+.RoundRecorder`), packed into a single int32 vector (flags 0/1, the two
+float32 observables as their bits, the per-class and per-agent counts;
+column layout :data:`~repro.telemetry.buffer.ROW_LAYOUT`), so a chunk's
+rows are one ``(rounds, 9 + 3K)`` int32 buffer that reaches the host in
+ONE device→host copy at the chunk boundary. There it is unpacked into
+numpy views, priced (Eq.-11 joules by UL/DL/SL class, wire bits) in
+float64 and appended to the :class:`~repro.telemetry.buffer.MetricBuffer`
+and sinks.
 No callbacks enter the trace, so buffered programs remain
 program-cache-admissible — they cache under a key extended with
 :meth:`Telemetry.trace_signature` — and the JX1/JX4 purity audits hold.
@@ -32,8 +36,10 @@ callback only observes.
 
 Sinks (:mod:`~repro.telemetry.sinks`) are pluggable: in-memory for
 tests, JSONL event log (schema-checked by
-``python -m repro.telemetry.schema``). ``report()`` adds the
-harness counters — ``scanloop.TRACE_COUNTS``, program-cache
+``python -m repro.telemetry.schema``). ``report()`` counts the
+device→host copies the chunk fetches made (``fetch_copies``, one per
+chunk: ``fetched_chunks``) and adds the harness counters —
+``scanloop.TRACE_COUNTS``, program-cache
 hits/misses/evictions, per-``ProgramRecord`` donation flags — so one
 call answers both "what did each round cost?" and "did the sweep
 recompile or recopy anything?".
@@ -83,6 +89,8 @@ class Telemetry:
                               or energy.paper_calibrated("fig3"))
         self.buffer = MetricBuffer(capacity)
         self._recorders: dict = {}      # id(engine) -> (engine, recorder)
+        self.fetch_copies = 0           # device→host copies of chunk rows
+        self.fetched_chunks = 0
 
     # -- identity of the traced program ---------------------------------
 
@@ -131,7 +139,8 @@ class Telemetry:
                 "jit (they are tracers, not values) — run the driver "
                 "outside jit, or use streaming mode, whose "
                 "jax.debug.callback emits from inside the trace")
-        with spans.span("telemetry.fetch"):
+        copies = self._count_fetch(rows)
+        with spans.span("telemetry.fetch", copies=copies):
             host = recorder.fetch(rows)
         with spans.span("telemetry.price"):
             events = recorder.finalize(host, int(start), driver=driver,
@@ -154,7 +163,8 @@ class Telemetry:
             raise ValueError(
                 "buffered telemetry cannot ingest meta metrics under an "
                 "outer jit — use streaming mode")
-        with spans.span("telemetry.fetch"):
+        copies = self._count_fetch(metrics)
+        with spans.span("telemetry.fetch", copies=copies):
             loss = np.asarray(metrics["meta_loss"])
             gn = metrics.get("meta_grad_norm")
             gn = None if gn is None else np.asarray(gn)
@@ -175,6 +185,14 @@ class Telemetry:
                     self._emit(e)
         return events
 
+    def _count_fetch(self, tree) -> int:
+        """Device→host copies a chunk fetch of ``tree`` makes (one per
+        device-array leaf), added to the run's totals."""
+        copies = sum(isinstance(x, jax.Array) for x in jax.tree.leaves(tree))
+        self.fetch_copies += copies
+        self.fetched_chunks += 1
+        return copies
+
     # -- streaming callbacks (called from INSIDE the chunk) -------------
 
     def stream_cb(self, recorder: RoundRecorder, driver: str = "fl",
@@ -185,10 +203,10 @@ class Telemetry:
         :meth:`record_rounds` does that in both modes, keeping buffer
         contents identical across modes)."""
         def cb(t, row):
-            if not bool(np.asarray(row["live"])):
-                return
-            self._emit(recorder.event(int(np.asarray(t)), row,
-                                      driver=driver, extra=extra))
+            e = recorder.event(int(np.asarray(t)), row, driver=driver,
+                               extra=extra)
+            if e["live"]:
+                self._emit(e)
         return cb
 
     def maml_stream_cb(self, extra: Optional[dict] = None):
@@ -236,6 +254,8 @@ class Telemetry:
             "dropped": self.buffer.dropped,
             "joules": sum(e.get("joules", 0.0) for e in live),
             "wire_bits": sum(e.get("wire_bits", 0.0) for e in live),
+            "fetch_copies": self.fetch_copies,
+            "fetched_chunks": self.fetched_chunks,
         }
         out.update(harness_report())
         return out
@@ -243,8 +263,10 @@ class Telemetry:
     # -- lifecycle ------------------------------------------------------
 
     def reset(self):
-        """Drop collected events (recorders and sinks stay)."""
+        """Drop collected events and fetch counts (recorders and sinks
+        stay)."""
         self.buffer.clear()
+        self.fetch_copies = self.fetched_chunks = 0
 
     def close(self):
         for sink in self.sinks:

@@ -3,10 +3,17 @@
 The scanned drivers cannot emit anything mid-chunk on the default path —
 a chunk is ONE compiled XLA program (see :mod:`repro.core.scanloop`) —
 so per-round observability has to ride the scan outputs: each round
-appends one fixed-shape ROW (a small dict of scalars) to the chunk's
-stacked ys, and the whole per-round buffer reaches the host in the same
-single device→host sync the driver already pays at the chunk boundary.
-That keeps the buffered path pure (no callbacks → JX1/JX4-clean and
+appends one fixed-shape ROW to the chunk's stacked ys. A row is ONE
+packed ``(F,)`` int32 vector, ``F = 9 + 3K`` columns laid out by
+:data:`ROW_LAYOUT` — the flags ``live``/``reached`` as 0/1, ``metric``
+and ``disagreement`` as their float32 bits
+(``lax.bitcast_convert_type``), the five int32 counts, then the three
+(K,) per-agent count vectors — so a chunk's rows are one ``(rounds,
+F)`` int32 buffer and reach the host in ONE device→host copy per chunk
+(:meth:`RoundRecorder.fetch`), unpacked there into numpy views. The
+bitcast loses no bits and the counts stay exact int32, so the priced
+stream is bit-for-bit what a dict of typed fields would give. That
+keeps the buffered path pure (no callbacks → JX1/JX4-clean and
 program-cache-admissible) and bit-parity trivial: the row computation
 reads the round's state, it never feeds back into it.
 
@@ -50,9 +57,19 @@ from repro.core import energy, topology as topo_lib
 #: per-agent attribution of the same aggregate ``n_*`` counts, summing
 #: exactly to them, and exactly zero for an agent that slept or whose
 #: every link died.
-ROW_FIELDS = ("live", "reached", "metric", "disagreement",
-              "n_sl", "n_ul", "n_dl", "n_active", "max_age",
-              "agent_sl", "agent_ul", "agent_dl")
+#:
+#: ``ROW_LAYOUT`` packs them, in this order, into one int32 vector:
+#: (field, how it is stored, columns) — ``flag`` 0/1, ``f32`` the
+#: float32 bits, ``i32`` as is; ``"K"`` columns = one per agent.
+ROW_LAYOUT = (
+    ("live", "flag", 1), ("reached", "flag", 1),
+    ("metric", "f32", 1), ("disagreement", "f32", 1),
+    ("n_sl", "i32", 1), ("n_ul", "i32", 1), ("n_dl", "i32", 1),
+    ("n_active", "i32", 1), ("max_age", "i32", 1),
+    ("agent_sl", "i32", "K"), ("agent_ul", "i32", "K"),
+    ("agent_dl", "i32", "K"),
+)
+ROW_FIELDS = tuple(name for name, _, _ in ROW_LAYOUT)
 
 
 def consensus_disagreement(stacked):
@@ -156,6 +173,13 @@ class RoundRecorder:
         if self.codec is not None:
             bits = self.codec.price_bits(bits)
         self._priced_bits = float(bits)
+        # each field's columns in the packed row; width F = 9 + 3K
+        self._columns, at = {}, 0
+        for name, _, width in ROW_LAYOUT:
+            n = K if width == "K" else 1
+            self._columns[name] = slice(at, at + n)
+            at += n
+        self.width = at
 
     # -- traced (inside the scan body) ----------------------------------
 
@@ -172,8 +196,11 @@ class RoundRecorder:
     @jax.named_scope("telemetry_row")
     def row(self, stacked, survival, *, metric, reached, live,
             active=None, age=None):
-        """One live round's row. ``survival`` is the PLAN-SHAPED
-        surviving-edge operand the round's mixing ACTUALLY used — from
+        """One live round's row, packed into one ``(F,)`` int32 vector
+        (:data:`ROW_LAYOUT`; :meth:`unpack` reads it back).
+
+        ``survival`` is the PLAN-SHAPED surviving-edge operand the
+        round's mixing ACTUALLY used — from
         ``engine.round_survival(t)``: (K, K) on dense-xla, (K, H) lanes
         on sparse-pallas/sharded, (M, K) slots on distributed (``None``
         on static graphs, where the counts are numpy constants folded
@@ -203,7 +230,7 @@ class RoundRecorder:
                    else jnp.max(jnp.where(jnp.asarray(self._real_mask),
                                           jnp.asarray(age, jnp.int32),
                                           jnp.int32(0))))
-        return {
+        return self._pack({
             "live": jnp.asarray(live, bool),
             "reached": jnp.asarray(reached, bool),
             "metric": jnp.asarray(metric, jnp.float32),
@@ -213,7 +240,7 @@ class RoundRecorder:
             "n_active": n_active, "max_age": max_age,
             "agent_sl": agents["SL"], "agent_ul": agents["UL"],
             "agent_dl": agents["DL"],
-        }
+        })
 
     def frozen_row(self):
         """The frozen ``lax.cond`` branch's row: all-zero, ``live`` off —
@@ -221,12 +248,24 @@ class RoundRecorder:
         bill."""
         z32 = jnp.int32(0)
         zk = jnp.zeros((self.topology.K,), jnp.int32)
-        return {"live": jnp.asarray(False), "reached": jnp.asarray(False),
-                "metric": jnp.float32(0.0),
-                "disagreement": jnp.float32(0.0),
-                "n_sl": z32, "n_ul": z32, "n_dl": z32,
-                "n_active": z32, "max_age": z32,
-                "agent_sl": zk, "agent_ul": zk, "agent_dl": zk}
+        return self._pack({
+            "live": jnp.asarray(False), "reached": jnp.asarray(False),
+            "metric": jnp.float32(0.0), "disagreement": jnp.float32(0.0),
+            "n_sl": z32, "n_ul": z32, "n_dl": z32,
+            "n_active": z32, "max_age": z32,
+            "agent_sl": zk, "agent_ul": zk, "agent_dl": zk})
+
+    @staticmethod
+    def _pack(fields) -> jax.Array:
+        """Row fields → the packed ``(F,)`` int32 row
+        (:data:`ROW_LAYOUT`)."""
+        parts = []
+        for name, kind, _ in ROW_LAYOUT:
+            v = fields[name]
+            if kind == "f32":
+                v = jax.lax.bitcast_convert_type(v, jnp.int32)
+            parts.append(jnp.reshape(jnp.asarray(v, jnp.int32), (-1,)))
+        return jnp.concatenate(parts)
 
     # -- host (once per chunk, after the sync) --------------------------
 
@@ -260,11 +299,36 @@ class RoundRecorder:
                         + int(a_ul) / p.E_UL + int(a_dl) / p.E_DL)
                 for a_sl, a_ul, a_dl in zip(agent_sl, agent_ul, agent_dl)]
 
-    @staticmethod
-    def fetch(rows) -> dict:
-        """Stacked chunk rows (device or numpy) → numpy arrays, one
-        device→host copy per field."""
-        return {k: np.asarray(v) for k, v in rows.items()}
+    def fetch(self, rows) -> dict:
+        """A chunk's stacked packed rows, ``(rounds, F)`` int32 on the
+        device → the host field arrays :meth:`finalize` reads: ONE
+        device→host copy, then numpy views (:meth:`unpack`)."""
+        return self.unpack(np.asarray(rows))
+
+    def unpack(self, packed) -> dict:
+        """Packed row(s), shape ``(..., F)`` → ``{field: array}`` with the
+        leading shape kept: flags as bool, ``metric``/``disagreement``
+        as float32 (a view of the same bits), counts as int32, and the
+        ``agent_*`` fields with a trailing K axis. A device array is
+        copied to the host first, in one copy."""
+        a = np.asarray(packed)
+        if a.dtype != np.int32 or a.shape[-1:] != (self.width,):
+            raise ValueError(
+                f"packed rows must be int32 (..., {self.width}) for "
+                f"K={self.topology.K}, got {a.dtype} {a.shape}; pass "
+                f"what RoundRecorder.row / frozen_row of this recorder "
+                f"returned")
+        host = {}
+        for name, kind, width in ROW_LAYOUT:
+            v = a[..., self._columns[name]]
+            if width != "K":
+                v = v[..., 0]
+            if kind == "f32":
+                v = v.view(np.float32)
+            elif kind == "flag":
+                v = v != 0
+            host[name] = v
+        return host
 
     def finalize(self, host, start: int, driver: str = "fl",
                  extra: Optional[dict] = None):
@@ -302,8 +366,9 @@ class RoundRecorder:
 
     def event(self, t: int, row, driver: str = "fl",
               extra: Optional[dict] = None) -> dict:
-        """One round's event (the streaming callback path)."""
-        single = {k: np.asarray(v)[None] for k, v in row.items()}
+        """One round's event from its packed ``(F,)`` row (the streaming
+        callback path)."""
+        single = self.unpack(np.asarray(row)[None])
         return self.finalize(single, start=int(t), driver=driver,
                              extra=extra)[0]
 

@@ -35,6 +35,8 @@ SPANS = tuple(PREFIX + n for n in (
     "driver.sync",          # waiting for the chunk outputs the driver reads
     "driver.bill",          # adapt_task's post-hoc Eq.-(11) bill
     "telemetry.fetch",      # a chunk's telemetry rows copied to the host
+                            # (arg copies: device→host copies it made,
+                            # 1 for a chunk's packed ledger rows)
     "telemetry.price",      # float64 pricing, buffer and sinks
 ))
 

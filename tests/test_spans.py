@@ -88,6 +88,23 @@ def test_adapt_task_spans_carry_the_task(recorded):
     assert tasks == [{"task_id": t} for t in range(6)]
 
 
+def test_every_ledger_fetch_makes_one_copy(recorded):
+    """Meta and FL chunks alike: each ``telemetry.fetch`` span says its
+    chunk's rows reached the host in one device→host copy."""
+    _, _, tree = recorded
+    fetches = []
+
+    def walk(nodes):
+        for name, args, kids in nodes:
+            if name == "telemetry.fetch":
+                fetches.append(args)
+            walk(kids)
+
+    walk(tree)
+    assert len(fetches) >= 7
+    assert all(args == {"copies": 1} for args in fetches), fetches
+
+
 def test_spans_leave_results_bit_identical(recorded):
     """Real spans with telemetry off give the recorded run's meta
     losses, t_i and rewards, bit for bit."""

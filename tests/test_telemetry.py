@@ -397,9 +397,170 @@ def test_per_agent_static_rows_match_link_classes():
         eng = ConsensusEngine(topo_lib.ring(K), plan=plan, **kw)
         rec = tl.RoundRecorder(eng)
         params = {"w": jnp.ones((K, D), jnp.float32)}
-        row = rec.row(params, None, metric=0.0, reached=False, live=True)
-        total = np.asarray(row["agent_sl"]) + np.asarray(row["agent_ul"]) \
-            + np.asarray(row["agent_dl"])
+        row = rec.unpack(rec.row(params, None, metric=0.0, reached=False,
+                                 live=True))
+        total = row["agent_sl"] + row["agent_ul"] + row["agent_dl"]
         rows[plan] = total
         assert (total == expected).all(), (plan, total, expected)
     assert all((v == rows["dense-xla"]).all() for v in rows.values())
+
+
+# ---------------------------------------------------------------------------
+# the packed row: one int32 vector per round, one copy per chunk
+# ---------------------------------------------------------------------------
+
+def _bits(x):
+    """The raw bits of a float32 array (NaN payloads and -0.0 kept)."""
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+def _f32(bits):
+    return np.array(bits, np.uint32).view(np.float32)
+
+
+#: metric bit patterns: negative, -0.0, -inf, a NaN with a payload and
+#: its sign bit set, the smallest negative subnormal
+_ODD_METRICS = (-1.5, -0.0, float("-inf"), _f32(0xFFC01234), _f32(0x80000001))
+
+
+@pytest.mark.parametrize("K", [2, 7])
+@pytest.mark.parametrize("plan,kw", [
+    ("dense-xla", {}), ("sparse-pallas", {}),
+    ("sharded", {"num_blocks": 1}), ("distributed", {})])
+def test_packed_rows_round_trip_bit_for_bit(plan, kw, K):
+    """Every field of a chunk's live and frozen rows, built on the plan's
+    own survival shape, comes back from ``fetch`` and ``unpack`` with
+    the bits it was packed with — odd float patterns and NaN
+    disagreement included."""
+    eng = ConsensusEngine(
+        topo_lib.ring(K), codec="int8:b64", plan=plan,
+        graph=topo_lib.GraphProcess.dropout(P_DROP, seed=DROP_SEED), **kw)
+    rec = tl.RoundRecorder(eng)
+    packed_fields = []
+    pack = rec._pack
+    rec._pack = lambda f: packed_fields.append(
+        {k: np.asarray(v) for k, v in f.items()}) or pack(f)
+    w = jax.random.normal(jax.random.PRNGKey(K), (K, D))
+    rows = []
+    for t, metric in enumerate(_ODD_METRICS):
+        params = {"w": w.at[0, 0].set(jnp.inf) if t == 2 else w * t}
+        rows.append(rec.row(params, eng.round_survival(jnp.int32(t)),
+                            metric=metric, reached=t % 2 == 1, live=True))
+    rows.append(rec.frozen_row())
+    host = rec.fetch(jnp.stack(rows))
+    assert len(packed_fields) == len(rows)
+    assert np.isnan(host["disagreement"][2])
+    assert sum(int(n) for n in host["n_sl"]) > 0, "no link survived"
+    for i, want in enumerate(packed_fields):
+        for name in tl.ROW_FIELDS:
+            got = host[name][i]
+            if want[name].dtype == np.float32:
+                assert got.dtype == np.float32, name
+                assert _bits(got) == _bits(want[name]), (i, name)
+            else:
+                assert got.dtype == want[name].dtype, name
+                np.testing.assert_array_equal(got, want[name], (i, name))
+        one = rec.unpack(rows[i])
+        assert all(_bits(one[n]) == _bits(host[n][i])
+                   for n in ("metric", "disagreement"))
+    assert not host["live"][-1] and host["agent_sl"].shape == (6, K)
+
+
+def test_packed_layout_keeps_odd_bits_and_int_extremes():
+    """A hand-built row: negative and NaN disagreement bits, the int32
+    extremes, a K-wide agent block — packed and read back exactly."""
+    eng = ConsensusEngine(topo_lib.ring(K))
+    rec = tl.RoundRecorder(eng)
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+    fields = {"live": np.bool_(True), "reached": np.bool_(False),
+              "metric": _f32(0x7F800001), "disagreement": np.float32(-3.25),
+              "n_sl": np.int32(lo), "n_ul": np.int32(hi), "n_dl": np.int32(-1),
+              "n_active": np.int32(K), "max_age": np.int32(7),
+              "agent_sl": np.arange(K, dtype=np.int32) - 3,
+              "agent_ul": np.full(K, hi, np.int32),
+              "agent_dl": np.full(K, lo, np.int32)}
+    packed = rec._pack({k: jnp.asarray(v) for k, v in fields.items()})
+    assert packed.shape == (rec.width,) == (9 + 3 * K,)
+    assert packed.dtype == jnp.int32
+    host = rec.unpack(np.asarray(packed))
+    for name, want in fields.items():
+        if want.dtype == np.float32:
+            assert _bits(host[name]) == _bits(want), name
+        else:
+            np.testing.assert_array_equal(host[name], want, name)
+    with pytest.raises(ValueError, match="packed rows"):
+        rec.unpack(np.asarray(packed)[:-1])
+
+
+class _LeafCounting(tl.Telemetry):
+    """Buffered telemetry that notes how many array leaves each chunk's
+    stacked rows have when they reach the host."""
+
+    def __init__(self):
+        super().__init__()
+        self.leaves = []
+
+    def record_rounds(self, recorder, rows, start, driver="fl",
+                      extra=None):
+        self.leaves.append(len(jax.tree.leaves(rows)))
+        return super().record_rounds(recorder, rows, start, driver, extra)
+
+
+def _drive_casestudy(tel):
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.rl.casestudy import CaseStudy
+    cfg = dataclasses.replace(get_arch("paper-dqn"), num_layers=2,
+                              d_model=32)
+    cs = CaseStudy(cfg=cfg, chunk=2, dropout_p=0.2, telemetry=tel)
+    key = jax.random.PRNGKey(0)
+    cs.adapt_task(key, 1, cs.init_params(key), max_rounds=4)
+
+
+def _drive_federated(tel):
+    _run(tel, 4, "dense-xla", max_rounds=8)
+
+
+def _drive_scan_rounds(tel):
+    eng = ConsensusEngine(topo_lib.ring(K))
+    eng.scan_rounds({"w": jnp.ones((K, D), jnp.float32)}, rounds=4,
+                    telemetry=tel)
+
+
+def _drive_trainer(tel):
+    from repro.configs import get_arch, reduced
+    from repro.launch.train import train_federated
+    cfg = reduced(get_arch("stablelm-3b"), num_layers=1, d_model=32)
+    train_federated(cfg, rounds=4, agents=2, tasks=1, local_steps=1,
+                    batch=2, seq=16, lr=1e-3, chunk=2, telemetry=tel)
+
+
+@pytest.mark.parametrize("drive", [_drive_casestudy, _drive_federated,
+                                   _drive_scan_rounds, _drive_trainer],
+                         ids=["casestudy", "federated", "scan_rounds",
+                              "trainer"])
+def test_each_driver_hands_over_one_packed_array_per_chunk(drive):
+    tel = _LeafCounting()
+    drive(tel)
+    assert tel.leaves and all(n == 1 for n in tel.leaves), tel.leaves
+    rep = tel.report()
+    assert rep["fetch_copies"] == rep["fetched_chunks"] == len(tel.leaves)
+
+
+def test_report_counts_one_copy_per_chunk_after_casestudy_run():
+    """A whole chunked process — meta chunks and FL chunks — fetches
+    each chunk's ledger rows in exactly one device→host copy."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.rl.casestudy import CaseStudy
+    cfg = dataclasses.replace(get_arch("paper-dqn"), num_layers=2,
+                              d_model=32)
+    tel = tl.Telemetry()
+    cs = CaseStudy(cfg=cfg, chunk=2, telemetry=tel)
+    res = cs.run(jax.random.PRNGKey(3), 2, max_rounds=4)
+    rep = tel.report()
+    fl_chunks = sum(-(-t // 2) for t in res.rounds_per_task)
+    assert rep["fetched_chunks"] == 1 + fl_chunks
+    assert rep["fetch_copies"] == rep["fetched_chunks"]
+    tel.reset()
+    assert tel.report()["fetch_copies"] == tel.report()["fetched_chunks"] == 0
